@@ -33,6 +33,7 @@ from benchmarks import (
     table16_slo,
     table17_autoscale,
 )
+from repro import compile_cache
 
 MODULES = [
     ("table1", table1_kernel_latency),
@@ -91,6 +92,7 @@ def main(argv: Sequence[str] | None = None) -> None:
             print(name, "-", doc.splitlines()[0] if doc else "(no description)")
         return
     picked = select(args.only)
+    compile_cache.enable()
     print("name,us_per_call,derived")
     failures = 0
     for name, mod in picked:

@@ -2,7 +2,9 @@
 
 The paper shows flat latency from 1 bank/1 FPGA to 2 banks/2 FPGAs. The
 TPU analogue shards the bank axis over devices with shard_map (zero
-cross-bank collectives). Runs in a subprocess with 2 host devices.
+cross-bank collectives). Runs in this process over ``jax.devices()`` and
+raises when fewer than two devices exist; on a CPU host, give it two with
+``XLA_FLAGS=--xla_force_host_platform_device_count=2`` before starting.
 
 This table also measures old-vs-new for the bank pipeline itself at the
 paper's default config (G=8, N=1000, 80×256): the *reference* path (what
@@ -14,132 +16,112 @@ program for all banks). The ratio is recorded to BENCH_denoise.json.
 
 from __future__ import annotations
 
-import os
-import subprocess
-import sys
-import textwrap
+import functools
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from benchmarks.common import PAPER_G, PAPER_H, PAPER_N, PAPER_W, bench_record, emit
+from repro.core.banks import banked_subtract_average, make_bank_mesh
+from repro.core.denoise import DenoiseConfig
+from repro.kernels import ops
+from repro.kernels.ref import ref_stream_finalize, ref_stream_step
 
-_CODE = textwrap.dedent("""
-    import os
-    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
-    import functools, time, numpy as np, jax, jax.numpy as jnp
-    from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.core.banks import banked_subtract_average, make_bank_mesh
-    from repro.core.denoise import DenoiseConfig
-    from repro.jax_compat import pcast_varying, shard_map
-    from repro.kernels.ref import ref_stream_step, ref_stream_finalize
 
-    N = int(os.environ.get("BANK_N", "200"))
-    FULL_N = int(os.environ.get("BANK_FULL_N", "1000"))
-    cfg = DenoiseConfig(num_groups=8, frames_per_group=N, height=80, width=256)
-    rng = np.random.default_rng(0)
+def _reference_banked(frames_u16, mesh, config):
+    """The pre-fusion path: host f32 convert, then a per-group scan of the
+    reference step per bank inside shard_map."""
+    x = jnp.asarray(frames_u16.astype(np.float32))
+    spec = P("bank", None, None, None, None)
 
-    def reference_banked(frames_u16, mesh, config):
-        # the pre-PR path: host f32 convert, then a per-group scan of the
-        # reference step per bank inside shard_map
-        x = jnp.asarray(frames_u16.astype(np.float32))
-        spec = P("bank", None, None, None, None)
+    @functools.partial(
+        jax.shard_map, mesh=mesh, in_specs=spec,
+        out_specs=P("bank", None, None, None),
+    )
+    def _per_bank(local):
+        def one(f):
+            g = f.shape[0]
 
-        @functools.partial(shard_map, mesh=mesh, in_specs=spec,
-                           out_specs=P("bank", None, None, None))
-        def _per_bank(local):
-            def one(f):
-                g = f.shape[0]
-                def body(s, grp):
-                    return ref_stream_step(s, grp, offset=config.offset,
-                        variant=config.variant, num_groups=g), None
-                init = pcast_varying(
-                    jnp.zeros((f.shape[1] // 2, f.shape[2], f.shape[3]),
-                              jnp.float32), ("bank",))
-                total, _ = jax.lax.scan(body, init, f)
-                return ref_stream_finalize(total, g, variant=config.variant)
-            return jax.vmap(one)(local)
+            def body(s, grp):
+                return ref_stream_step(
+                    s, grp, offset=config.offset, variant=config.variant,
+                    num_groups=g,
+                ), None
 
-        return _per_bank(jax.device_put(x, NamedSharding(mesh, spec)))
+            init = jax.lax.pcast(
+                jnp.zeros((f.shape[1] // 2, f.shape[2], f.shape[3]), jnp.float32),
+                ("bank",), to="varying",
+            )
+            total, _ = jax.lax.scan(body, init, f)
+            return ref_stream_finalize(total, g, variant=config.variant)
 
-    def fused_banked(frames_u16, mesh, config):
-        # the new path: u16 straight to device, fused ops dispatch
-        return banked_subtract_average(jnp.asarray(frames_u16), mesh,
-                                       config=config)
+        return jax.vmap(one)(local)
 
-    def bench(fn, x, mesh, config, iters=3):
-        jax.block_until_ready(fn(x, mesh, config))  # compile
-        ts = []
-        for _ in range(iters):
-            t0 = time.perf_counter()
-            jax.block_until_ready(fn(x, mesh, config))
-            ts.append(time.perf_counter() - t0)
-        return min(ts)
+    return _per_bank(jax.device_put(x, NamedSharding(mesh, spec)))
 
-    # -- scaling: 1 vs 2 banks, fused path --------------------------------
-    def scaling(banks):
-        mesh = make_bank_mesh(banks)
-        x = rng.integers(0, 4096,
-            (banks, cfg.num_groups, cfg.frames_per_group, 80, 256)
-        ).astype(np.uint16)
-        return bench(fused_banked, x, mesh, cfg)
 
-    t1 = scaling(1)
-    t2 = scaling(2)
-    print(f"BANKS,{t1:.4f},{t2:.4f},{t2 / t1:.3f}")
+def _fused_banked(frames_u16, mesh, config):
+    """The fused path: u16 straight to device, fused ops dispatch."""
+    return banked_subtract_average(jnp.asarray(frames_u16), mesh, config=config)
 
-    # -- old vs new at the paper default config (single bank) -------------
-    pcfg = DenoiseConfig(num_groups=8, frames_per_group=FULL_N,
-                         height=80, width=256)
-    mesh1 = make_bank_mesh(1)
-    xp = rng.integers(0, 4096,
-        (1, pcfg.num_groups, pcfg.frames_per_group, 80, 256)).astype(np.uint16)
-    t_ref = bench(reference_banked, xp, mesh1, pcfg)
-    t_fused = bench(fused_banked, xp, mesh1, pcfg)
-    # parity while we're here
-    a = np.asarray(reference_banked(xp, mesh1, pcfg))
-    b = np.asarray(fused_banked(xp, mesh1, pcfg))
-    np.testing.assert_allclose(a, b, rtol=1e-5)
-    print(f"FUSED,{t_ref:.4f},{t_fused:.4f},{t_ref / t_fused:.3f}")
-""")
+
+def _bench(fn, x, mesh, config, iters=3):
+    jax.block_until_ready(fn(x, mesh, config))  # compile
+    ts = []
+    for _ in range(iters):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(x, mesh, config))
+        ts.append(time.perf_counter() - t0)
+    return min(ts)
 
 
 def run(quick: bool = True) -> None:
-    # BANK_FULL_N stays at paper scale even in quick mode: the recorded
-    # trajectory point must be at the paper default config (~25 s here).
-    env = dict(
-        os.environ, BANK_N="100" if quick else "400", BANK_FULL_N=str(PAPER_N)
-    )
-    env.pop("XLA_FLAGS", None)
-    try:
-        out = subprocess.run(
-            [sys.executable, "-c", _CODE], capture_output=True, text=True,
-            env=env, timeout=1800,
-        )
-    except subprocess.TimeoutExpired as e:
-        tail = (e.stderr or b"")[-200:] if isinstance(e.stderr, bytes) else ""
-        emit("table5/multibank", -1, f"TIMEOUT after {e.timeout}s {tail}")
-        return
-    lines = {
-        l.split(",")[0]: l.split(",")
-        for l in out.stdout.splitlines()
-        if l.startswith(("BANKS", "FUSED"))
-    }
-    if "BANKS" not in lines or "FUSED" not in lines:
-        emit("table5/multibank", -1, f"FAILED:{out.stderr[-200:]}")
-        return
-    _, t1, t2, ratio = lines["BANKS"]
-    emit("table5/one_bank", float(t1) * 1e6, "elapsed_us_total")
+    # the fused-vs-reference point stays at paper scale even in quick mode:
+    # the recorded trajectory point must be at the paper default config
+    n = 100 if quick else 400
+    rng = np.random.default_rng(0)
+    cfg = DenoiseConfig(num_groups=8, frames_per_group=n, height=80, width=256)
+
+    def scaling(banks):
+        mesh = make_bank_mesh(banks)  # raises when fewer devices exist
+        x = rng.integers(
+            0, 4096, (banks, cfg.num_groups, cfg.frames_per_group, 80, 256)
+        ).astype(np.uint16)
+        return _bench(_fused_banked, x, mesh, cfg)
+
+    t1, t2 = scaling(1), scaling(2)
+    emit("table5/one_bank", t1 * 1e6, "elapsed_us_total")
     emit(
         "table5/two_banks",
-        float(t2) * 1e6,
-        f"scaling_ratio={ratio} (paper: 1.00 flat; host devices share ONE "
-        "physical core here, so ~2x is the serialization ceiling — the "
-        "shard_map program has zero cross-bank collectives, verified in "
+        t2 * 1e6,
+        f"scaling_ratio={t2 / t1:.3f} (paper: 1.00 flat; the shard_map "
+        "program has zero cross-bank collectives, verified in "
         "tests/test_banks.py)",
     )
-    _, t_ref, t_fused, speedup = lines["FUSED"]
+
+    pcfg = DenoiseConfig(
+        num_groups=PAPER_G, frames_per_group=PAPER_N, height=PAPER_H,
+        width=PAPER_W,
+    )
+    mesh1 = make_bank_mesh(1)
+    xp = rng.integers(
+        0, 4096, (1, pcfg.num_groups, pcfg.frames_per_group, PAPER_H, PAPER_W)
+    ).astype(np.uint16)
+    t_ref = _bench(_reference_banked, xp, mesh1, pcfg)
+    t_fused = _bench(_fused_banked, xp, mesh1, pcfg)
+    np.testing.assert_allclose(
+        np.asarray(_reference_banked(xp, mesh1, pcfg)),
+        np.asarray(_fused_banked(xp, mesh1, pcfg)),
+        rtol=1e-5,
+    )
+    speedup = t_ref / t_fused
     emit(
         "table5/fused_vs_reference",
-        float(t_fused) * 1e6,
-        f"reference_us={float(t_ref) * 1e6:.1f};speedup={speedup}x "
+        t_fused * 1e6,
+        f"reference_us={t_ref * 1e6:.1f};speedup={speedup:.3f}x "
         "(paper default G=8,N=1000,80x256, single bank)",
     )
     bench_record(
@@ -151,7 +133,7 @@ def run(quick: bool = True) -> None:
             "H": PAPER_H,
             "W": PAPER_W,
             "banks": 1,
-            "backend": "xla",
+            "backend": ops._resolve(pcfg.backend),
         },
         baseline="reference (host f32 + per-group ref_stream_step scan)",
         candidate="fused (u16 in, subtract fused into group reduction)",

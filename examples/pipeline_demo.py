@@ -20,7 +20,7 @@ P_STAGES, M, MB, D = 4, 8, 4, 64
 ws = jax.random.normal(jax.random.PRNGKey(0), (P_STAGES, D, D)) / jnp.sqrt(D)
 xs = jax.random.normal(jax.random.PRNGKey(1), (M, MB, D))
 
-from repro.jax_compat import make_mesh
+from repro.mesh import make_mesh
 
 mesh = make_mesh((P_STAGES,), ("stage",))
 out = pipeline_forward(

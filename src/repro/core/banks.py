@@ -10,10 +10,11 @@ communication-free scaling the paper exploits.
 The per-shard body dispatches through the ``ops`` backend layer, so each
 device runs the *fast* path for its platform: the fused multi-bank Pallas
 kernel on TPU (grid over the device's local banks), the fused batched XLA
-program elsewhere — never the per-group reference scan. Older-JAX quirks
-(no ``jax.shard_map``, no ``jax.lax.pcast``) are absorbed by
-``repro.jax_compat``; the pcast varying-cast is applied only when the
-installed JAX has a varying-type system.
+program elsewhere — never the per-group reference scan. The bank mesh
+comes from ``repro.mesh.make_mesh`` (Auto axes), so the serve tier's slot
+scatter/gather runs on bank-sharded state. The shard bodies run with
+``check_vma=False``: they are bank-local (no collectives), and a Pallas
+kernel's output shape carries no varying-axes annotation.
 
 Streaming ingest composes with the ring-buffer pipeline
 (``repro.core.ringbuf``): ``run_pipelined_banked`` gives every bank shard
@@ -26,9 +27,9 @@ one-DRAM-pipeline-per-FPGA topology, hosting any ``repro.denoise`` filter
 ``banked_stream_step``; other filters shard their own state pytrees via
 ``StreamingFilter.state_pspec``).
 
-On this CPU container the mesh has a single device unless the caller brings
-a multi-device mesh (tests spawn subprocesses with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8``).
+On a CPU host the mesh has a single device unless the process starts with
+``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (the multi-device
+tests spawn such subprocesses).
 """
 
 from __future__ import annotations
@@ -47,8 +48,8 @@ from repro.core.denoise import DenoiseConfig
 from repro.core.ringbuf import RingBuffer, RingClosed
 from repro.core.streaming import _stream_report
 from repro.denoise import get_filter
-from repro.jax_compat import shard_map
 from repro.kernels import ops
+from repro.mesh import make_mesh
 
 __all__ = [
     "make_bank_mesh",
@@ -65,7 +66,7 @@ def make_bank_mesh(num_banks: int | None = None) -> Mesh:
     n = num_banks or len(devs)
     if len(devs) < n:
         raise ValueError(f"need {n} devices for {n} banks, have {len(devs)}")
-    return jax.make_mesh((n,), ("bank",), devices=devs[:n])
+    return make_mesh((n,), ("bank",), devices=devs[:n])
 
 
 def banked_subtract_average(
@@ -84,7 +85,8 @@ def banked_subtract_average(
     tiles = tune.tile_args(config, "stream")  # once, before the shard body
 
     @functools.partial(
-        shard_map, mesh=mesh, in_specs=spec, out_specs=P("bank", None, None, None)
+        jax.shard_map, mesh=mesh, in_specs=spec,
+        out_specs=P("bank", None, None, None), check_vma=False,
     )
     def _per_bank(local):  # local: (B/banks, G, N, H, W)
         return ops.multibank_subtract_average(
@@ -113,10 +115,11 @@ def banked_stream_step(
     tiles = tune.tile_args(config, "stream")  # once, before the shard body
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P("bank", None, None, None), P("bank", None, None, None)),
         out_specs=P("bank", None, None, None),
+        check_vma=False,
     )
     def _step(s, f):
         return ops.multibank_stream_step(
@@ -202,10 +205,11 @@ def banked_filter_step(
     specs = filt.state_pspec(state)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(specs, _chunk_spec()),
         out_specs=specs,
+        check_vma=False,
     )
     def _step(local_state, local_chunk):
         return filt.step(local_state, local_chunk, step_index=step_index)

@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.jax_compat import pcast_varying, shard_map
 
 __all__ = ["pipeline_forward", "bubble_fraction"]
 
@@ -59,7 +58,7 @@ def pipeline_forward(
     pspec = jax.tree_util.tree_map(lambda _: P(axis), stage_params)
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(pspec, P()),
         out_specs=P(),
@@ -88,8 +87,8 @@ def pipeline_forward(
             outs = jnp.where(valid, updated, outs)
             return (y, outs), None
 
-        buf0 = pcast_varying(jnp.zeros_like(xs[0]), (axis,))
-        outs0 = pcast_varying(jnp.zeros_like(xs), (axis,))
+        buf0 = jax.lax.pcast(jnp.zeros_like(xs[0]), (axis,), to="varying")
+        outs0 = jax.lax.pcast(jnp.zeros_like(xs), (axis,), to="varying")
         (buf, outs), _ = jax.lax.scan(
             tick, (buf0, outs0), jnp.arange(total)
         )

@@ -56,7 +56,8 @@ def _ema_kernel(
     k = pl.program_id(1)
     acc = o_ema.dtype
     diff = quant.pair_diff_block(
-        f_ref[...], offset=offset, accum_dtype=acc, stream_dtype=stream_dtype
+        f_ref[...], offset=offset, accum_dtype=acc, stream_dtype=stream_dtype,
+        in_kernel=True,
     )
     a = jnp.asarray(alpha, acc)
     o_ema[...] = ema_ref[...] * (1 - a) + a * diff
@@ -105,7 +106,7 @@ def ema_welford_step(
     pair_tile: int | None = None,
     stream_dtype: str = "u16",
     placement: str | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fold one group into (ema, wmean, wm2); all three state arrays donated.
 

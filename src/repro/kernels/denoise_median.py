@@ -44,7 +44,8 @@ def _insert_kernel(f_ref, w_ref, o_ref, *, offset: float, stream_dtype: str):
     acc = o_ref.dtype
     # f_ref: (tp, 2, th, wire_w) -> diff (tp, th, w) = o_ref block (slot squeezed)
     diff = quant.pair_diff_block(
-        f_ref[...], offset=offset, accum_dtype=acc, stream_dtype=stream_dtype
+        f_ref[...], offset=offset, accum_dtype=acc, stream_dtype=stream_dtype,
+        in_kernel=True,
     )
     o_ref[...] = diff
 
@@ -72,7 +73,7 @@ def median_window_insert(
     pair_tile: int | None = None,
     stream_dtype: str = "u16",
     placement: str | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Write the group's diff frames into ``window[slot]`` (window donated).
 
@@ -101,6 +102,15 @@ def median_window_insert(
         _insert_kernel, offset=float(offset), stream_dtype=stream_dtype
     )
     ms = spaces.operand_spaces("median_insert", placement)
+    # aliased donor; the kernel never reads it. Left in ANY/HBM it is the
+    # whole unblocked window (Mosaic takes no blocks there).
+    if ms.get("donor") is pl.ANY:
+        donor = pl.BlockSpec(memory_space=pl.ANY)
+    else:
+        donor = pl.BlockSpec(
+            (None, tp, th, w), lambda k, hb: (slot, k, hb, 0),
+            memory_space=ms.get("donor"),
+        )
     return pl.pallas_call(
         kernel,
         grid=(p // tp, h // th),
@@ -109,11 +119,7 @@ def median_window_insert(
                 (tp, 2, th, wp), lambda k, hb: (k, 0, hb, 0),
                 memory_space=ms.get("pairs"),
             ),
-            # aliased donor; kernel never reads it
-            pl.BlockSpec(
-                (None, tp, th, w), lambda k, hb: (slot, k, hb, 0),
-                memory_space=ms.get("donor"),
-            ),
+            donor,
         ],
         out_specs=pl.BlockSpec(
             (None, tp, th, w), lambda k, hb: (slot, k, hb, 0),
@@ -152,7 +158,7 @@ def median_combine(
     row_tile: int | None = None,
     pair_tile: int | None = None,
     placement: str | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """(K, N/2, H, W) window -> (N/2, H, W) per-pixel median over K.
 

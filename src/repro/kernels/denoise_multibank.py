@@ -47,7 +47,8 @@ def _mb_kernel(
     acc = o_ref.dtype
     # f_ref: (pair_tile, 2, th, wire_w) for this (bank, pair_block, row_block, group)
     diff = quant.pair_diff_block(
-        f_ref[...], offset=offset, accum_dtype=acc, stream_dtype=stream_dtype
+        f_ref[...], offset=offset, accum_dtype=acc, stream_dtype=stream_dtype,
+        in_kernel=True,
     )
     if divide_first:
         diff = diff / jnp.asarray(num_groups, acc)
@@ -88,7 +89,7 @@ def multibank_subtract_average(
     pair_tile: int | None = None,
     stream_dtype: str = "u16",
     placement: str | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """frames (B, G, N, H, wire_W) -> (B, N/2, H, W), one fused ``pallas_call``."""
     b, g, n, h, wp = frames.shape
@@ -135,7 +136,8 @@ def _mb_step_kernel(
 ):
     acc = o_ref.dtype
     diff = quant.pair_diff_block(
-        f_ref[...], offset=offset, accum_dtype=acc, stream_dtype=stream_dtype
+        f_ref[...], offset=offset, accum_dtype=acc, stream_dtype=stream_dtype,
+        in_kernel=True,
     )
     if divide_first:
         diff = diff / jnp.asarray(num_groups, acc)
@@ -172,7 +174,7 @@ def multibank_stream_step(
     pair_tile: int | None = None,
     stream_dtype: str = "u16",
     placement: str | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fold one group per bank (B, N, H, wire_W) into sums (B, N/2, H, W).
 

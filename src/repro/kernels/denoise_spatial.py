@@ -99,7 +99,7 @@ def spatial_filter_3x3(
     row_tile: int | None = None,
     pair_tile: int | None = None,
     placement: str | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """(P, H, W) -> (P, H, W): 3×3 box or bilateral-lite smoothing per frame.
 

@@ -87,7 +87,8 @@ def _alg3_kernel(
     acc = o_ref.dtype
     # f_ref: (pair_tile, 2, th, wire_w) -> dequantized diff (pair_tile, th, w)
     diff = quant.pair_diff_block(
-        f_ref[...], offset=offset, accum_dtype=acc, stream_dtype=stream_dtype
+        f_ref[...], offset=offset, accum_dtype=acc, stream_dtype=stream_dtype,
+        in_kernel=True,
     )
     if divide_first:
         diff = diff / jnp.asarray(num_groups, acc)
@@ -128,7 +129,7 @@ def alg3_subtract_average(
     pair_tile: int | None = None,
     stream_dtype: str = "u16",
     placement: str | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """frames (G, N, H, wire_W) -> averaged difference frames (N/2, H, W).
 
@@ -190,7 +191,8 @@ def _alg3_step_kernel(
 ):
     acc = o_ref.dtype
     diff = quant.pair_diff_block(
-        f_ref[...], offset=offset, accum_dtype=acc, stream_dtype=stream_dtype
+        f_ref[...], offset=offset, accum_dtype=acc, stream_dtype=stream_dtype,
+        in_kernel=True,
     )
     if divide_first:
         diff = diff / jnp.asarray(num_groups, acc)
@@ -227,7 +229,7 @@ def alg3_stream_step(
     pair_tile: int | None = None,
     stream_dtype: str = "u16",
     placement: str | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Fold one group (N, H, wire_W) into the running sum (N/2, H, W) (donated)."""
     n, h, wp = group_frames.shape

@@ -113,7 +113,7 @@ def alg1_subtract_average(
     *,
     offset: float = 0.0,
     accum_dtype=jnp.float32,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Algorithm 1: tmpFrame in HBM, single-row (non-burst) R and W."""
     return _two_pass(
@@ -135,7 +135,7 @@ def alg2_subtract_average(
     offset: float = 0.0,
     accum_dtype=jnp.float32,
     row_tile: int | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ):
     """Algorithm 2: burst-mode writes (large tiles), row-granular reads."""
     g, n, h, w = frames.shape

@@ -233,6 +233,12 @@ def subtract_average(
                     f"no {stream_dtype!r} ingest for the {algorithm} pallas "
                     "baseline; use backend='xla' or stream_dtype='u16'"
                 )
+            if not interp:
+                raise ValueError(
+                    f"the {algorithm} pallas baseline moves single-row "
+                    "blocks, which Mosaic refuses on TPU; use backend='xla' "
+                    "or interpret=True"
+                )
             fn = (
                 denoise_tmpframe.alg1_subtract_average
                 if algorithm == "alg1"

@@ -32,6 +32,7 @@ layer already imports the kernels.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -175,44 +176,91 @@ def decode(wire: np.ndarray, stream_dtype: str) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def dequant(x, stream_dtype: str, accum_dtype) -> jnp.ndarray:
+def dequant(x, stream_dtype: str, accum_dtype, *, in_kernel: bool = False) -> jnp.ndarray:
     """Wire values ``(..., wire_w)`` -> pixel values ``(..., W)`` in
     ``accum_dtype``.
 
     Pure elementwise/reshape jnp — valid both inside a Pallas kernel body
     (on block values already resident in VMEM) and in the XLA fallbacks.
-    The ``"u16"`` path is exactly the pre-tier ``astype``, preserving
-    bit-identity.
+    Unsigned containers widen through int32: Mosaic has no direct
+    unsigned->float cast, and every 8- or 16-bit container value is exact
+    in int32 and float32, so the ``"u16"`` path stays bit-identical to a
+    plain cast (float frames are cast directly).
+
+    ``in_kernel`` picks the ``"p12"`` unpack Mosaic compiles (selection
+    matmuls, O(W^2) per row); the XLA fallbacks keep the O(W) triplet
+    reshape. Both are exact, so the two give identical pixels.
     """
     acc = jnp.dtype(accum_dtype)
     validate_stream_dtype(stream_dtype)
-    if stream_dtype == "u16":
-        return x.astype(acc)
+    if stream_dtype == "p12":
+        unpack = _unpack12_select if in_kernel else _unpack12_reshape
+        return unpack(x).astype(acc)
+    if jnp.issubdtype(x.dtype, jnp.unsignedinteger):
+        x = x.astype(jnp.int32)
     if stream_dtype == "u8":
         return x.astype(acc) * jnp.asarray(U8_SCALE, acc)
+    return x.astype(acc)
+
+
+def _unpack12_reshape(x) -> jnp.ndarray:
+    """Packed-12-bit bytes ``(..., 3W/2)`` -> uint16 pixels ``(..., W)``:
+    pixel ``2i`` is ``b0 | (b1 & 0xF) << 8`` and pixel ``2i+1`` is
+    ``b1 >> 4 | b2 << 4`` of byte triplet ``i``."""
     wp = x.shape[-1]
-    w = logical_width(wp, stream_dtype)
+    w = logical_width(wp, "p12")
     trip = x.reshape(x.shape[:-1] + (wp // 3, 3)).astype(jnp.uint16)
     b0, b1, b2 = trip[..., 0], trip[..., 1], trip[..., 2]
     lo = b0 | ((b1 & 0xF) << 8)
     hi = (b1 >> 4) | (b2 << 4)
-    return (
-        jnp.stack([lo, hi], axis=-1)
-        .reshape(x.shape[:-1] + (w,))
-        .astype(acc)
-    )
+    return jnp.stack([lo, hi], axis=-1).reshape(x.shape[:-1] + (w,))
 
 
-def pair_diff_block(block, *, offset: float, accum_dtype, stream_dtype: str = "u16"):
+def _unpack12_select(x) -> jnp.ndarray:
+    """:func:`_unpack12_reshape` in a form Mosaic compiles.
+
+    Mosaic cannot split the lane axis into triplets, so the bytes are
+    gathered to pixel positions by two 0/1 selection matmuls instead:
+    ``prim`` holds each pixel's own byte (b0 for even pixels, b2 for odd
+    ones) and ``mid`` the shared b1. Bytes are exact in bfloat16 and each
+    output sums one product, so the gather is exact on every backend.
+    """
+    wp = x.shape[-1]
+    w = logical_width(wp, "p12")
+    src = jax.lax.broadcasted_iota(jnp.int32, (wp, w), 0)
+    dst = jax.lax.broadcasted_iota(jnp.int32, (wp, w), 1)
+    base = 3 * (dst >> 1)
+    sel_prim = (src == base + 2 * (dst & 1)).astype(jnp.bfloat16)
+    sel_mid = (src == base + 1).astype(jnp.bfloat16)
+    rows = x.reshape(-1, wp).astype(jnp.int32).astype(jnp.float32)
+    rows = rows.astype(jnp.bfloat16)
+
+    def gather(sel):
+        return jnp.dot(rows, sel, preferred_element_type=jnp.float32).astype(
+            jnp.int32
+        )
+
+    prim, mid = gather(sel_prim), gather(sel_mid)
+    odd = jax.lax.broadcasted_iota(jnp.int32, prim.shape, 1) & 1
+    lo = prim | ((mid & 0xF) << 8)
+    hi = (mid >> 4) | (prim << 4)
+    return jnp.where(odd == 1, hi, lo).reshape(x.shape[:-1] + (w,))
+
+
+def pair_diff_block(
+    block, *, offset: float, accum_dtype, stream_dtype: str = "u16",
+    in_kernel: bool = False,
+):
     """The shared kernel prologue: ``(..., 2, th, wire_w)`` pairs block ->
     dequantized ``(..., th, W)`` difference ``exc - ctl + offset``.
 
     Every ingest kernel family (stream, multibank, median insert, EMA) and
     every XLA fallback runs this exact sequence, so the subtraction
     arithmetic — and therefore the numeric stream — is identical across
-    backends for each wire format.
+    backends for each wire format. Kernel bodies pass ``in_kernel=True``
+    (see :func:`dequant`).
     """
     acc = jnp.dtype(accum_dtype)
-    ctl = dequant(block[..., 0, :, :], stream_dtype, acc)
-    exc = dequant(block[..., 1, :, :], stream_dtype, acc)
+    ctl = dequant(block[..., 0, :, :], stream_dtype, acc, in_kernel=in_kernel)
+    exc = dequant(block[..., 1, :, :], stream_dtype, acc, in_kernel=in_kernel)
     return exc - ctl + jnp.asarray(offset, acc)

@@ -2,7 +2,7 @@
 
 ``repro.tune.budget.FAMILY_PLACEMENTS`` describes *where each logical
 operand of a kernel family should live* as plain strings ("vmem",
-"smem", "any"); this module translates those strings into the Pallas TPU
+"smem", "any"); this module translates those strings into the Pallas
 memory-space objects a ``pl.BlockSpec`` accepts, so the kernel files can
 write::
 
@@ -16,43 +16,34 @@ VMEM accumulators, SMEM scalars (the EMA traced step counter), and
 ANY/HBM for operands the kernel never reads (the median insert's aliased
 donor slot).
 
-Placement is *advisory* and numerics-neutral: ``None`` from
-:func:`memory_space` (unknown string, or a jax build without the Pallas
-TPU module) leaves the BlockSpec unannotated and the compiler places the
-operand exactly as before this tier. The autotuner searches scheme names
-(``budget.placement_schemes``) and caches the measured winner in the
-plan; kernels receive the scheme name as a static ``placement`` arg.
+Placement is numerics-neutral: ``None`` from :func:`memory_space` leaves
+the BlockSpec unannotated and the compiler places the operand. The
+autotuner searches scheme names (``budget.placement_schemes``) and caches
+the measured winner in the plan; kernels receive the scheme name as a
+static ``placement`` arg.
 """
 
 from __future__ import annotations
 
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
 from repro.tune import budget
 
-__all__ = ["memory_space", "operand_spaces", "available"]
+__all__ = ["memory_space", "operand_spaces"]
 
-try:  # pallas TPU memory spaces exist even off-TPU (interpret mode)
-    from jax.experimental.pallas import tpu as _pltpu
-
-    _SPACES = {
-        "vmem": _pltpu.VMEM,
-        "smem": _pltpu.SMEM,
-        "any": _pltpu.ANY,
-    }
-except Exception:  # pragma: no cover - pallas-less jax build
-    _pltpu = None
-    _SPACES = {}
-
-
-def available() -> bool:
-    """True when this jax build exposes Pallas TPU memory spaces."""
-    return bool(_SPACES)
+_SPACES = {
+    "vmem": pltpu.VMEM,
+    "smem": pltpu.SMEM,
+    "any": pl.ANY,
+}
 
 
 def memory_space(space: str | None):
     """Space string -> Pallas memory-space object (None = unannotated)."""
     if space is None:
         return None
-    return _SPACES.get(space)
+    return _SPACES[space]
 
 
 def operand_spaces(family: str, placement: str | None = None) -> dict:

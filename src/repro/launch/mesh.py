@@ -10,19 +10,15 @@ links).
 
 from __future__ import annotations
 
-from repro import jax_compat
+from repro.mesh import make_mesh
 
-__all__ = ["make_production_mesh", "make_mesh", "HW"]
+__all__ = ["make_production_mesh", "HW"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax_compat.make_mesh(shape, axes)
-
-
-def make_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
-    return jax_compat.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 class HW:

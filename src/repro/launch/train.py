@@ -31,7 +31,7 @@ from repro.checkpoint import CheckpointManager
 from repro.configs import ARCH_IDS, get_config
 from repro.distributed import sharding as sh
 from repro.launch import steps
-from repro.launch.mesh import make_mesh
+from repro.mesh import make_mesh
 from repro.models import build_model
 from repro.optim import AdamW, cosine_schedule
 from repro.optim import compress as C

@@ -27,8 +27,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import jax_compat
 from repro.distributed import sharding as sh
+from repro.mesh import make_mesh
 
 __all__ = [
     "available_mesh",
@@ -67,7 +67,7 @@ def available_mesh(axis_names=("data", "model"), *, devices=None):
     """Largest power-of-2 mesh over the surviving devices."""
     devs = list(devices if devices is not None else jax.devices())
     shape = mesh_shape(len(devs), len(axis_names))
-    return jax_compat.make_mesh(
+    return make_mesh(
         shape, axis_names, devices=devs[: int(np.prod(shape))]
     )
 

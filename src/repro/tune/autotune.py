@@ -111,9 +111,13 @@ def tile_candidates(
         in_pixel_bytes=in_pixel_bytes,
     )
     cands: list[tuple[int, int]] = []
+    # the legacy pick need not sit on the sublane tiling Mosaic takes
+    legal = budget.legal_row_tiles(
+        family, h, in_dtype=in_dtype, acc_dtype=acc_dtype
+    )
 
     def add(th: int, tp: int) -> None:
-        if h % th == 0 and p % tp == 0 and (th, tp) not in cands:
+        if th in legal and p % tp == 0 and (th, tp) not in cands:
             cands.append((th, tp))
 
     add(*budget.resolve_tiles(family, p, h, w, **kw))

@@ -24,7 +24,9 @@ family              block working set (per grid step)
 overrides are validated (must divide exactly — Mosaic-friendly blocks,
 interpret-mode exactness), and the heuristic fills the budget with the
 largest exact divisors, rows first (the paper's burst-length-first
-ordering). The measured autotuner (``repro.tune.autotune``) uses the same
+ordering). Heuristic row tiles are multiples of the block dtypes' native
+sublane tiling (8 rows of f32, 16 of u16, 32 of u8) or the full height,
+so every pick is one Mosaic compiles without relayouts. The measured autotuner (``repro.tune.autotune``) uses the same
 model to generate its candidate set, so tuned plans search *around* the
 budget point instead of blindly.
 
@@ -47,6 +49,7 @@ __all__ = [
     "KernelBudget",
     "largest_divisor_leq",
     "block_bytes",
+    "legal_row_tiles",
     "pick_row_tile",
     "pick_pair_tile",
     "resolve_tiles",
@@ -159,6 +162,24 @@ def _bytes(dtype) -> int:
     return int(np.dtype(dtype).itemsize)
 
 
+def _sublane_rows(dtype) -> int:
+    """Rows in one native (rows, 128) VMEM tile of ``dtype``: 8 for 32-bit,
+    16 for 16-bit and 32 for 8-bit values."""
+    return 8 * max(1, 4 // _bytes(dtype))
+
+
+def legal_row_tiles(
+    family: str, h: int, *, in_dtype="uint16", acc_dtype="float32"
+) -> list[int]:
+    """Row tiles Mosaic takes natively for ``family``, ascending: exact
+    divisors of ``h`` that keep every operand on its native sublane tiling
+    (the narrowest dtype in the block decides), and always the full height."""
+    align = _sublane_rows(acc_dtype)
+    if _family(family).in_planes:
+        align = max(align, _sublane_rows(in_dtype))
+    return [d for d in range(align, h, align) if h % d == 0] + [h]
+
+
 def placement_schemes(family: str) -> tuple[str, ...]:
     """Valid placement scheme names for ``family``, default first."""
     _family(family)
@@ -237,20 +258,22 @@ def pick_row_tile(
     in_pixel_bytes: float | None = None,
     vmem_budget: int = VMEM_BUDGET,
 ) -> int:
-    """Largest exact divisor of ``h`` whose single-pair block fits the budget.
+    """Largest legal row tile of ``h`` whose single-pair block fits the
+    budget; the smallest legal one when none fits.
 
-    Rows are sized first (at ``pair_tile=1``); ``pick_pair_tile`` then
-    fills the remaining budget — the same order as the legacy pickers, so
-    plans stay comparable across the refactor.
+    Legal tiles are the sublane-aligned exact divisors of ``h`` and ``h``
+    itself (:func:`legal_row_tiles`). Rows are sized first (at
+    ``pair_tile=1``); ``pick_pair_tile`` then fills the remaining budget —
+    the same order as the legacy pickers, so plans stay comparable.
     """
     per_row = block_bytes(
         family, 1, 1, w, in_dtype=in_dtype, acc_dtype=acc_dtype, window=window,
         in_pixel_bytes=in_pixel_bytes,
     )
     rows = max(1, vmem_budget // max(1, per_row))
-    if rows >= h:
-        return h
-    return largest_divisor_leq(h, rows)
+    legal = legal_row_tiles(family, h, in_dtype=in_dtype, acc_dtype=acc_dtype)
+    fitting = [t for t in legal if t <= rows]
+    return fitting[-1] if fitting else legal[0]
 
 
 def pick_pair_tile(
@@ -302,7 +325,8 @@ def resolve_tiles(
 
     Explicit overrides win but must divide exactly (a non-dividing tile
     raises ``ValueError`` — on TPU it would force masked edge blocks, in
-    interpret mode it would be silently wrong).
+    interpret mode it would be silently wrong). Picked row tiles are
+    always sublane-aligned or the full height (:func:`legal_row_tiles`).
     """
     kw = dict(
         in_dtype=in_dtype, acc_dtype=acc_dtype, window=window,
@@ -313,14 +337,21 @@ def resolve_tiles(
         # across pair blocks, so pair_tile is NUMERICS-VISIBLE (different
         # blocking => different float rounding). The default therefore
         # stays pinned to the exact pre-tuner pick — bit-identical
-        # heuristic output — and may overshoot the corrected budget by a
-        # bounded factor (<= ~2x: the old model ignored the f32-vs-u16
-        # input gap and the mean/M2 row planes). The corrected operand
-        # model still bounds the measured-search candidates, where
-        # changing numerics is explicit opt-in (tile_plan="auto").
+        # heuristic output — wherever that pick is a legal row tile and
+        # its block stays within 2x the corrected budget (the old model
+        # ignored the f32-vs-u16 input gap and the mean/M2 row planes).
+        # Elsewhere the corrected model below picks, as for every family.
         th = row_tile or legacy_pick_row_tile(h, w)
         tp = pair_tile or legacy_pick_pair_tile(p, th, w)
-        return _check_divides(th, tp, p=p, h=h)
+        legal = row_tile or th in legal_row_tiles(
+            family, h, in_dtype=in_dtype, acc_dtype=acc_dtype
+        )
+        within = block_bytes(
+            family, th, tp, w, in_dtype=in_dtype, acc_dtype=acc_dtype,
+            in_pixel_bytes=in_pixel_bytes,
+        ) <= 2 * vmem_budget
+        if legal and within:
+            return _check_divides(th, tp, p=p, h=h)
     th = row_tile or pick_row_tile(family, h, w, **kw)
     tp = pair_tile or pick_pair_tile(family, p, th, w, **kw)
     return _check_divides(th, tp, p=p, h=h)

@@ -65,7 +65,7 @@ def test_restore_with_shardings(tmp_path):
 
     mgr = CheckpointManager(str(tmp_path))
     mgr.save(0, _state(), blocking=True)
-    from repro.jax_compat import make_mesh as _make_mesh
+    from repro.mesh import make_mesh as _make_mesh
     mesh = _make_mesh((1,), ("data",))
     sh = jax.tree_util.tree_map(
         lambda _: NamedSharding(mesh, P()), _state()

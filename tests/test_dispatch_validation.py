@@ -40,6 +40,16 @@ def test_subtract_average_unknown_backend_lists_backends():
     _assert_lists(exc, ops.BACKENDS)
 
 
+@pytest.mark.parametrize("algorithm", ["alg1", "alg2"])
+def test_compiled_single_row_baselines_are_refused(algorithm):
+    """The Alg 1/2 Pallas baselines move 1-row blocks, which Mosaic does
+    not take: compiled (not interpreted) they fail loudly at dispatch."""
+    with pytest.raises(ValueError, match="single-row"):
+        ops.subtract_average(
+            FRAMES, algorithm=algorithm, backend="pallas", interpret=False
+        )
+
+
 def test_multibank_unknown_algorithm_and_backend():
     with pytest.raises(ValueError) as exc:
         ops.multibank_subtract_average(BANKED, algorithm="alg0")

@@ -284,7 +284,7 @@ class TestElastic:
             from repro.runtime.elastic import elastic_reshard
             spec = {"w": ParamSpec((8, 16), ("embed", "mlp"))}
             state = {"w": jnp.arange(128, dtype=jnp.float32).reshape(8, 16)}
-            from repro.jax_compat import make_mesh
+            from repro.mesh import make_mesh
             mesh8 = make_mesh((4, 2), ("data", "model"))
             sharded = jax.tree_util.tree_map(
                 jax.device_put, state, named_shardings(spec, mesh8))

@@ -87,7 +87,7 @@ def test_collectives_counted_with_trips():
         import jax, jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.roofline import hlo_costs
-        from repro.jax_compat import make_mesh
+        from repro.mesh import make_mesh
         mesh = make_mesh((4,), ("m",))
         sh = NamedSharding(mesh, P(None, "m"))
         rep = NamedSharding(mesh, P())

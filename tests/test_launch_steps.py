@@ -22,7 +22,7 @@ def test_train_step_lowers_on_small_mesh():
         import jax
         from repro.configs import get_config
         from repro.launch import steps
-        from repro.launch.mesh import make_mesh
+        from repro.mesh import make_mesh
         from repro.models import build_model
         from repro.optim import AdamW
 
@@ -47,7 +47,7 @@ def test_decode_step_lowers_on_small_mesh():
         import jax
         from repro.configs import get_config
         from repro.launch import steps
-        from repro.launch.mesh import make_mesh
+        from repro.mesh import make_mesh
         from repro.models import build_model
 
         cfg = get_config("gemma3-1b", smoke=True)

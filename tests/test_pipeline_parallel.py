@@ -22,7 +22,7 @@ def test_pipeline_matches_sequential():
         def stage_fn(w, x):
             return jnp.tanh(x @ w)
 
-        from repro.jax_compat import make_mesh
+        from repro.mesh import make_mesh
         mesh = make_mesh((P_STAGES,), ("stage",))
         out = pipeline_forward({"w": ws}, xs, mesh,
                                lambda p, x: stage_fn(p["w"], x))

@@ -125,6 +125,18 @@ def test_dequant_matches_host_decode(sd):
         np.testing.assert_array_equal(dev, host)
 
 
+def test_p12_kernel_unpack_matches_xla_unpack_on_every_value():
+    """The selection-matmul unpack kernels use decodes every 12-bit value
+    in both pixel positions exactly as the XLA fallbacks' reshape does."""
+    lo = np.arange(quant.MONO12_MAX + 1, dtype=np.uint16)
+    frames = np.stack([lo, lo[::-1]], axis=-1).reshape(32, 256)
+    wire = jnp.asarray(quant.encode(frames, "p12"))
+    kernel = quant.dequant(wire, "p12", jnp.float32, in_kernel=True)
+    xla = quant.dequant(wire, "p12", jnp.float32)
+    np.testing.assert_array_equal(np.asarray(kernel), np.asarray(xla))
+    np.testing.assert_array_equal(np.asarray(xla), frames.astype(np.float32))
+
+
 def test_pair_diff_block_u16_matches_plain_arithmetic():
     """The shared prologue on u16 wire IS the pre-tier astype arithmetic."""
     frames = _mono12((5, 2, 8, 16), seed=4)  # (pairs, 2, th, W)

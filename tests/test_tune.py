@@ -64,12 +64,12 @@ def test_resolve_tiles_divides_and_fits(family, p, h, w):
     th, tp = budget.resolve_tiles(family, p, h, w, window=window)
     assert h % th == 0 and p % tp == 0
     bb = budget.block_bytes(family, th, tp, w, window=window)
-    # within budget, unless even a single row overflows (then minimal
-    # rows). "ema" is pinned to the legacy pick for bit-compatibility
-    # (its Chan merge makes pair_tile numerics-visible), so it may
-    # overshoot the corrected accounting by a bounded factor.
+    # within budget, unless even the smallest legal row tile overflows
+    # (then that tile). "ema" is pinned to the legacy pick for
+    # bit-compatibility (its Chan merge makes pair_tile numerics-visible),
+    # so it may overshoot the corrected accounting by a bounded factor.
     cap = budget.VMEM_BUDGET * (2 if family == "ema" else 1)
-    assert bb <= cap or th == 1
+    assert bb <= cap or th == budget.legal_row_tiles(family, h)[0]
 
 
 def test_resolve_tiles_rejects_non_dividing_overrides():
@@ -115,12 +115,47 @@ def test_property_resolve_tiles_exact_divisors_within_budget():
         bb = budget.block_bytes(
             family, th, tp, w, in_dtype=in_dtype, window=window
         )
+        # over budget only when even the smallest legal row tile overflows
+        smallest = budget.legal_row_tiles(family, h, in_dtype=in_dtype)[0]
         # ema at the default budget runs the bit-compat legacy pick
-        # (bounded <= ~2x overshoot); everything else fits exactly
+        # (bounded <= 2x overshoot); everything else fits exactly
         if family == "ema" and budget_bytes == budget.VMEM_BUDGET:
-            assert bb <= 2 * budget_bytes or th == 1
+            assert bb <= 2 * budget_bytes or th == smallest
         else:
-            assert bb <= budget_bytes or th == 1
+            assert bb <= budget_bytes or th == smallest
+
+    check()
+
+
+#: native sublane rows of one VMEM tile: 8 of f32, 16 of 16-bit, 32 of 8-bit
+SUBLANE_ROWS = {"float32": 8, "bfloat16": 16, "uint16": 16, "uint8": 32}
+
+
+def test_property_resolve_tiles_sublane_aligned():
+    """Picked row tiles sit on the input dtype's native sublane tiling (a
+    f32 accumulator never allows fewer than 8 rows), or span the full
+    height: the only row blocks Mosaic takes without relayouts."""
+    pytest.importorskip(
+        "hypothesis", reason="dev-only dependency (see requirements-dev.txt)"
+    )
+    from hypothesis import given, settings, strategies as st
+
+    @settings(max_examples=300, deadline=None)
+    @given(
+        family=st.sampled_from(sorted(budget.KERNEL_FAMILIES)),
+        p=st.integers(1, 1024),
+        h=st.integers(1, 1024),
+        w=st.sampled_from([128, 256, 384, 2048]),
+        in_dtype=st.sampled_from(sorted(SUBLANE_ROWS)),
+        budget_bytes=st.sampled_from([2**14, 2**18, budget.VMEM_BUDGET]),
+    )
+    def check(family, p, h, w, in_dtype, budget_bytes):
+        th, _ = budget.resolve_tiles(
+            family, p, h, w, in_dtype=in_dtype, vmem_budget=budget_bytes
+        )
+        has_input = budget.KERNEL_FAMILIES[family].in_planes > 0
+        align = SUBLANE_ROWS[in_dtype] if has_input else 8
+        assert th == h or (th % align == 0 and h % th == 0), (th, align)
 
     check()
 
@@ -142,9 +177,10 @@ def test_shared_model_matches_legacy_picks_at_production_shapes():
 
 def test_ema_heuristic_pinned_to_legacy_pick():
     """The EMA kernel's Chan merge makes pair_tile numerics-visible, so
-    its heuristic stays pinned to the pre-tuner pick at EVERY shape —
-    including ones where the corrected accounting would diverge (p=96,
-    f32 input: corrected budget would pick 6, legacy picks 8)."""
+    its heuristic stays pinned to the pre-tuner pick wherever that pick
+    is a legal row tile within 2x the budget — including shapes where the
+    corrected accounting would diverge (p=96, f32 input: corrected budget
+    would pick 6, legacy picks 8)."""
     for p, h, w in [(96, 80, 256), (56, 80, 256), (500, 80, 256)]:
         th_legacy = _pick_row_tile(h, w)
         tp_legacy = _pick_pair_tile(p, th_legacy, w)
@@ -171,6 +207,20 @@ def test_ema_heuristic_pinned_to_legacy_pick():
 
     for a, b in zip(step(None, None), step(th, tp)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("h,in_dtype,want", [
+    (65, "float32", 65),   # no aligned divisor: the full height is the floor
+    (72, "float32", 24),   # legacy 72 rows would be 2.25x the budget
+    (80, "uint16", 16),    # legacy 80 rows: 2.3x; 16-row u16 tiles fit
+])
+def test_ema_pin_yields_where_legacy_pick_overruns(h, in_dtype, want):
+    """At w=2048 the legacy pick's one-pair block overruns 2x the
+    corrected budget; the corrected model then picks a legal tile."""
+    th, tp = budget.resolve_tiles("ema", 1, h, 2048, in_dtype=in_dtype)
+    assert (th, tp) == (want, 1)
+    bb = budget.block_bytes("ema", th, tp, 2048, in_dtype=in_dtype)
+    assert bb <= budget.VMEM_BUDGET or th == h
 
 
 def test_heuristic_output_bit_identical_to_legacy_tiles():
