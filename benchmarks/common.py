@@ -96,9 +96,9 @@ def bench_record(name: str, kind: str, **fields) -> None:
     Every point is additionally stamped with a monotone ``run_seq``
     (``max`` over the file's existing stamps, plus one — derived from
     file contents inside the same read-modify-replace cycle, so it is
-    exactly as crash-safe as the append itself). The regression sentinel
-    (``scripts/bench_regress.py``) orders a family's points by it instead
-    of trusting wall-clock timestamps, which CI runners skew freely.
+    exactly as crash-safe as the append itself), so a reader can order a
+    family's points by it instead of trusting wall-clock timestamps,
+    which CI runners skew freely.
     """
     if not kind or not isinstance(kind, str):
         raise ValueError(f"bench_record needs a non-empty kind, got {kind!r}")

@@ -180,4 +180,5 @@ def ema_welford_step(
         ],
         input_output_aliases={1: 0, 2: 1, 3: 2},
         interpret=interpret,
+        name="ema_welford_step",
     )(pairs, ema, wmean, wm2, prior)

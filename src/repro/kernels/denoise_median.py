@@ -128,6 +128,7 @@ def median_window_insert(
         out_shape=jax.ShapeDtypeStruct(window.shape, window.dtype),
         input_output_aliases={1: 0},
         interpret=interpret,
+        name="median_window_insert",
     )(pairs, window)
 
 
@@ -188,4 +189,5 @@ def median_combine(
         ),
         out_shape=jax.ShapeDtypeStruct((p, h, w), window.dtype),
         interpret=interpret,
+        name="median_combine",
     )(window)

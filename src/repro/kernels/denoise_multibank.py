@@ -127,6 +127,7 @@ def multibank_subtract_average(
         ),
         out_shape=jax.ShapeDtypeStruct((b, p, h, w), jnp.dtype(accum_dtype)),
         interpret=interpret,
+        name="multibank_subtract_average",
     )(pairs)
 
 
@@ -220,4 +221,5 @@ def multibank_stream_step(
         out_shape=jax.ShapeDtypeStruct(sum_frames.shape, sum_frames.dtype),
         input_output_aliases={1: 0},
         interpret=interpret,
+        name="multibank_stream_step",
     )(pairs, sum_frames)

@@ -146,4 +146,5 @@ def spatial_filter_3x3(
         ),
         out_shape=jax.ShapeDtypeStruct(frames.shape, frames.dtype),
         interpret=interpret,
+        name="spatial_filter_3x3",
     )(frames, frames, frames)

@@ -173,6 +173,7 @@ def alg3_subtract_average(
         ),
         out_shape=jax.ShapeDtypeStruct((p, h, w), jnp.dtype(accum_dtype)),
         interpret=interpret,
+        name="alg3_subtract_average",
     )(pairs)
 
 
@@ -271,4 +272,5 @@ def alg3_stream_step(
         out_shape=jax.ShapeDtypeStruct(sum_frame.shape, sum_frame.dtype),
         input_output_aliases={1: 0},
         interpret=interpret,
+        name="alg3_stream_step",
     )(pairs, sum_frame)

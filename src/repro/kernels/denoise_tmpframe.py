@@ -63,6 +63,7 @@ def _two_pass(
     write_tile: int,
     read_tile: int,
     interpret: bool,
+    name: str,
 ):
     g, n, h, w = frames.shape
     p = n // 2
@@ -85,6 +86,7 @@ def _two_pass(
         ),
         out_shape=jax.ShapeDtypeStruct((g, p, h, w), acc),
         interpret=interpret,
+        name=name,
     )(pairs)
 
     # Pass B: read tmpFrame back and average (paper line 21).
@@ -101,6 +103,7 @@ def _two_pass(
         out_specs=pl.BlockSpec((None, read_tile, w), lambda k, hb, gi: (k, hb, 0)),
         out_shape=jax.ShapeDtypeStruct((p, h, w), acc),
         interpret=interpret,
+        name=name,
     )(tmp)
     return out
 
@@ -123,6 +126,7 @@ def alg1_subtract_average(
         write_tile=1,
         read_tile=1,
         interpret=interpret,
+        name="alg1_subtract_average",
     )
 
 
@@ -149,4 +153,5 @@ def alg2_subtract_average(
         write_tile=th,
         read_tile=1,
         interpret=interpret,
+        name="alg2_subtract_average",
     )

@@ -21,8 +21,6 @@ On top of the two halves sits the judgement tier (PR 9):
 * :mod:`repro.obs.health` — fleet ``HealthReport`` (imported lazily by
   ``FleetScheduler.health()``/``scripts/healthz.py``; not re-exported
   here because its capacity model reaches into ``repro.core``).
-* :mod:`repro.obs.regress` — noise-aware perf-regression sentinel over
-  ``BENCH_denoise.json`` point families (``scripts/bench_regress.py``).
 
 See docs/ARCHITECTURE.md ("Observability layer" and "SLO & health
 tier") for the span/metric taxonomy and the layering contract.

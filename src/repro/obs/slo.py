@@ -222,7 +222,7 @@ class SloEngine:
         self.specs = specs
         self.registry = registry
         self.tracer = tracer  # None -> the process-default tracer at emit time
-        self.clock = clock if clock is not None else _trace._MonotonicClock()
+        self.clock = clock if clock is not None else _trace._PerfCounterClock()
         self.eval_every_s = eval_every_s
         self._lock = threading.Lock()
         self._last_eval = -math.inf

@@ -9,11 +9,13 @@ bound. ``export_chrome()`` renders the ring as Chrome trace-event JSON —
 load the file at ``chrome://tracing`` or https://ui.perfetto.dev.
 
 Determinism is a design input, not an afterthought: the clock is
-injectable (any object with a ``.now() -> float`` method, duck-type
-compatible with ``repro.serve.faults.FakeClock`` — deliberately *not*
-imported here, so ``repro.obs`` stays stdlib-only), and B/E ordering is
-tie-broken by a global sequence number drawn at span entry *and* exit, so
-traces taken under a frozen fake clock still nest correctly.
+injectable (default ``time.perf_counter``, the clock of the serve
+scheduler's timers and of the benchmark's stamps; any object with a
+``.now() -> float`` method, duck-type compatible with
+``repro.serve.faults.FakeClock`` — deliberately *not* imported here,
+so ``repro.obs`` stays stdlib-only), and B/E ordering is tie-broken
+by a global sequence number drawn at span entry *and* exit, so traces
+taken under a frozen fake clock still nest correctly.
 
 The disabled path is the hot path. ``Tracer(enabled=False).span(...)``
 returns one preallocated no-op context manager and touches no lock, no
@@ -23,7 +25,15 @@ overhead ratio of exactly this path to ≤ 2%.
 
 Optional ``annotate=True`` additionally wraps every span in
 ``jax.profiler.TraceAnnotation`` so obs spans line up with XLA ops in a
-device profile; JAX is imported lazily and absence degrades to no-op.
+device profile; JAX is imported lazily and absence degrades to no-op. The
+span's args (and its thread CPU time) reach the profile as event stats at
+the span's end. The first span opened after enabling while the profiler
+records is preceded by one ``obs.clock_sync`` span, so that a Chrome
+export and the profile share an event to align their clocks on.
+
+Enabled spans also read the thread's CPU time (``time.thread_time``) at
+both ends; the Chrome export carries it as ``tts``, so a long span shows
+whether its thread ran or stood still.
 """
 
 from __future__ import annotations
@@ -55,11 +65,12 @@ DEFAULT_MAX_EVENTS = 65536
 _seq = itertools.count()  # global tie-breaker for equal timestamps
 
 
-class _MonotonicClock:
-    """Default wall clock; same shape as ``serve.faults.Clock``."""
+class _PerfCounterClock:
+    """Default clock, ``time.perf_counter``; same shape as
+    ``serve.faults.Clock``."""
 
     def now(self) -> float:
-        return time.monotonic()
+        return time.perf_counter()
 
 
 class _NullSpan:
@@ -83,7 +94,9 @@ _NULL_SPAN = _NullSpan()
 class Span:
     """A live span; records itself into the tracer ring on ``__exit__``."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_seq0", "_annotation")
+    __slots__ = (
+        "_tracer", "name", "cat", "args", "_t0", "_tt0", "_seq0", "_annotation"
+    )
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
         self._tracer = tracer
@@ -91,6 +104,7 @@ class Span:
         self.cat = cat
         self.args = args
         self._t0 = 0.0
+        self._tt0 = 0.0
         self._seq0 = 0
         self._annotation = None
 
@@ -99,18 +113,26 @@ class Span:
         self.args.update(args)
 
     def __enter__(self):
+        tracer = self._tracer
+        if tracer._sync_pending:
+            tracer._clock_sync()
         # Draw the B-side sequence number *now*: under a frozen FakeClock
         # an outer span must still sort before the inner span it contains.
         self._seq0 = next(_seq)
-        self._t0 = self._tracer.clock.now()
-        ann = self._tracer._annotation_cls
+        self._t0 = tracer.clock.now()
+        self._tt0 = time.thread_time()
+        ann = tracer._annotation_cls
         if ann is not None:
             self._annotation = ann(self.name)
             self._annotation.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
+        tt1 = time.thread_time()
         if self._annotation is not None:
+            self._annotation.set_metadata(
+                **self.args, thread_cpu_ms=(tt1 - self._tt0) * 1e3
+            )
             self._annotation.__exit__(exc_type, exc, tb)
         t1 = self._tracer.clock.now()
         self._tracer._record(
@@ -120,6 +142,8 @@ class Span:
                 "cat": self.cat,
                 "t0": self._t0,
                 "t1": t1,
+                "tt0": self._tt0,
+                "tt1": tt1,
                 "seq0": self._seq0,
                 "seq1": next(_seq),
                 "tid": threading.get_ident(),
@@ -141,11 +165,13 @@ class Tracer:
         enabled: bool = True,
         annotate: bool = False,
     ):
-        self.clock = clock if clock is not None else _MonotonicClock()
+        self.clock = clock if clock is not None else _PerfCounterClock()
         self.enabled = enabled
         self._events: collections.deque = collections.deque(maxlen=max_events)
         self._lock = threading.Lock()
         self._annotation_cls = _load_annotation_cls() if annotate else None
+        #: an ``obs.clock_sync`` span is owed to the profile (see _clock_sync)
+        self._sync_pending = enabled and self._annotation_cls is not None
 
     # -- write side ----------------------------------------------------------
     def span(self, name: str, cat: str = "", **args) -> Any:
@@ -189,6 +215,27 @@ class Tracer:
     def _record(self, ev: dict) -> None:
         with self._lock:
             self._events.append(ev)
+
+    def _clock_sync(self) -> None:
+        """Emit the owed ``obs.clock_sync`` span once the profiler records.
+
+        The span goes through the ``TraceAnnotation`` bridge into the
+        profile and into this tracer's ring alike, so the Chrome export
+        and the profile share one event to align their clocks on. It
+        waits for a recording profiler (a program may enable tracing
+        before it starts one); the ``obs.`` prefix keeps it from naming
+        idle gaps in the benchmark's breakdown.
+        """
+        ann = self._annotation_cls
+        recording = getattr(ann, "is_enabled", None)
+        if ann is None or (recording is not None and not recording()):
+            return
+        with self._lock:
+            if not self._sync_pending:
+                return
+            self._sync_pending = False
+        with Span(self, "obs.clock_sync", "obs", {"wall_ns": time.time_ns()}):
+            pass
 
     # -- read side -----------------------------------------------------------
     def events(self) -> list[dict]:
@@ -239,12 +286,13 @@ class Tracer:
             if ev["kind"] == "span":
                 ts0 = (ev["t0"] - epoch) * 1e6
                 ts1 = (ev["t1"] - epoch) * 1e6
-                out.append(
-                    (ts0, ev["seq0"], {**base, "name": ev["name"], "ph": "B", "ts": ts0, "args": args})
-                )
-                out.append(
-                    (ts1, ev["seq1"], {**base, "name": ev["name"], "ph": "E", "ts": ts1})
-                )
+                # tts: the thread's CPU time, Chrome's thread timestamp
+                b = {**base, "name": ev["name"], "ph": "B", "ts": ts0,
+                     "tts": ev["tt0"] * 1e6, "args": args}
+                e = {**base, "name": ev["name"], "ph": "E", "ts": ts1,
+                     "tts": ev["tt1"] * 1e6}
+                out.append((ts0, ev["seq0"], b))
+                out.append((ts1, ev["seq1"], e))
             else:
                 ts0 = (ev["t0"] - epoch) * 1e6
                 out.append(
@@ -369,6 +417,9 @@ def configure(
         t.clock = clock
     if annotate is not None:
         t._annotation_cls = _load_annotation_cls() if annotate else None
+    if enabled is not None or annotate is not None:
+        # each enabling with the bridge owes the profile one clock_sync
+        t._sync_pending = t.enabled and t._annotation_cls is not None
     if max_events is not None:
         with t._lock:
             t._events = collections.deque(t._events, maxlen=max_events)

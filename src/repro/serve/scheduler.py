@@ -59,6 +59,15 @@ Topology (one ``_SlotExecutor`` shown; the scheduler pools several)::
   rings, plus a soft ``deadline_ms`` per group (misses counted in the
   report). Per-group service latency (staged → step done) feeds the
   p50/p95/p99 columns of :class:`SessionReport`.
+* **Stage timing.** Each stage of a served group is a ``serve.*`` span
+  (``serve.h2d`` on the acquisition thread; ``serve.admit``,
+  ``serve.coalesce``, ``serve.dispatch`` then ``serve.block`` as
+  siblings, ``serve.finalize`` and ``serve.wait`` on the executor
+  thread) and, whether tracing is on or not, a per-session instrument in
+  the scheduler registry: ``serve.h2d_s`` and ``serve.backpressure_s``
+  (counters), ``serve.coalesce_s`` and ``serve.step_s`` (one sample per
+  group; each ``serve.latency_s`` sample is ring dwell + ``serve.step_s``)
+  and ``serve.finalize_s`` (one sample per session).
 * **Multi-device.** Pass a ``bank`` mesh and each executor's slot array
   is laid out bank-sharded via ``shard_map`` (one session per device
   slot). Mesh executors gang-schedule: a step waits until every occupied
@@ -74,6 +83,7 @@ from __future__ import annotations
 
 import collections
 import functools
+import itertools
 import math
 import threading
 import time
@@ -142,12 +152,19 @@ class _Active:
         # labeled by session; SessionReport columns derive from it (_report).
         self.metrics = metrics if metrics is not None else obs.MetricsRegistry()
         self.c_transfer = self.metrics.counter("serve.transfer_s", session=self.name)
+        self.c_h2d = self.metrics.counter("serve.h2d_s", session=self.name)
+        self.c_backpressure = self.metrics.counter(
+            "serve.backpressure_s", session=self.name
+        )
         self.c_compute = self.metrics.counter("serve.compute_s", session=self.name)
         self.c_misses = self.metrics.counter("serve.deadline_misses", session=self.name)
         self.c_discarded = self.metrics.counter("serve.discarded", session=self.name)
         # per-group service latency samples (staged -> step done), bounded
         # reservoir so endless streams stay O(1)
         self.h_latency = self.metrics.histogram("serve.latency_s", session=self.name)
+        self.h_coalesce = self.metrics.histogram("serve.coalesce_s", session=self.name)
+        self.h_step = self.metrics.histogram("serve.step_s", session=self.name)
+        self.h_finalize = self.metrics.histogram("serve.finalize_s", session=self.name)
         self.error: BaseException | None = None
         # -- fleet bookkeeping (inert under the plain scheduler) ------------
         self.executor = None          # the _SlotExecutor currently hosting us
@@ -186,19 +203,29 @@ class _Active:
         real-time session sheds stale groups exactly like a running one).
         """
         src = self.session.chunks()
+        name = self.name
+        stats = self.ring.stats
         try:
-            while True:
+            for group in itertools.count():
                 t0 = time.perf_counter()
                 try:
                     chunk = next(src)
                 except StopIteration:
                     break
-                dev = jax.device_put(jnp.asarray(chunk))
-                jax.block_until_ready(dev)
+                t_pulled = time.perf_counter()
+                with obs.span("serve.h2d", "serve", session=name, group=group):
+                    dev = jax.device_put(jnp.asarray(chunk))
+                    jax.block_until_ready(dev)
+                t_landed = time.perf_counter()
+                self.c_h2d.inc(t_landed - t_pulled)
                 # staged-time bookkeeping lives in the ring itself (its
                 # per-slot put timestamps are taken post-backpressure), so
-                # the item carries only the transfer cost
-                self.ring.put((dev, time.perf_counter() - t0))
+                # the item carries only the transfer cost; this thread is
+                # the ring's only producer, so the growth of its put wait
+                # across the put is this put's backpressure
+                wait0 = stats.put_wait_s
+                self.ring.put((dev, t_landed - t0))
+                self.c_backpressure.inc(stats.put_wait_s - wait0)
         except RingClosed:
             pass  # executor detached us (leave/shutdown/error)
         except BaseException as e:  # source failure -> fail the session
@@ -331,7 +358,8 @@ class _SlotExecutor:
             with self.cond:
                 # hooks (ring put/close, enqueue, leave) wake us; the
                 # timeout is a safety net against a lost edge, not a poll
-                self.cond.wait_for(self._wake_needed, timeout=0.05)
+                with obs.span("serve.wait", "serve", executor=self.name):
+                    self.cond.wait_for(self._wake_needed, timeout=0.05)
                 if self._abort:
                     break
                 if self._shutdown and not self.pending and not any(self.slots):
@@ -430,35 +458,39 @@ class _SlotExecutor:
                 self.slots[idx] = act
                 joins.append((idx, act))
         for idx, act in joins:
-            # fresh single-bank state into the vacant slot — or, for a
-            # fleet-resumed/migrated session, its checkpointed slot state.
-            # Either way the banked shapes are unchanged, so the batched
-            # step is NOT retraced by the join.
-            seed = act.resume_state
-            act.resume_state = None
-            self.state = self._insert_slot(
-                self.state, seed if seed is not None else self.filt.init(), idx
-            )
-            # re-fold the chunks the crash lost between the last
-            # checkpoint and the failure — same chunks, same order, same
-            # step indices, so the resumed state is bit-identical to the
-            # pre-crash one before any new chunk is touched
-            if act.pending_replay:
-                obs.instant(
-                    "serve.replay",
-                    "serve",
-                    session=act.name,
-                    executor=self.name,
-                    chunks=len(act.pending_replay),
-                    from_step=act.steps,
+            with obs.span(
+                "serve.admit", "serve", session=act.name, group=act.steps,
+                executor=self.name, slot=idx,
+            ):
+                # fresh single-bank state into the vacant slot — or, for a
+                # fleet-resumed/migrated session, its checkpointed slot state.
+                # Either way the banked shapes are unchanged, so the batched
+                # step is NOT retraced by the join.
+                seed = act.resume_state
+                act.resume_state = None
+                self.state = self._insert_slot(
+                    self.state, seed if seed is not None else self.filt.init(), idx
                 )
-            while act.pending_replay:
-                chunk = act.pending_replay.pop(0)
-                sub = self.filt.slot_extract(self.state, idx)
-                new = self.filt.step(sub, chunk, step_index=act.steps)
-                self.state = self._insert_slot(self.state, new, idx)
-                act.steps += 1
-                act.frames += math.prod(chunk.shape[:-2])
+                # re-fold the chunks the crash lost between the last
+                # checkpoint and the failure — same chunks, same order, same
+                # step indices, so the resumed state is bit-identical to the
+                # pre-crash one before any new chunk is touched
+                if act.pending_replay:
+                    obs.instant(
+                        "serve.replay",
+                        "serve",
+                        session=act.name,
+                        executor=self.name,
+                        chunks=len(act.pending_replay),
+                        from_step=act.steps,
+                    )
+                while act.pending_replay:
+                    chunk = act.pending_replay.pop(0)
+                    sub = self.filt.slot_extract(self.state, idx)
+                    new = self.filt.step(sub, chunk, step_index=act.steps)
+                    self.state = self._insert_slot(self.state, new, idx)
+                    act.steps += 1
+                    act.frames += math.prod(chunk.shape[:-2])
             if act.t_joined is None:
                 act.t_joined = time.perf_counter()
             act.handle.status = "active"
@@ -526,18 +558,24 @@ class _SlotExecutor:
                     act.c_discarded.inc()
             if not act.finished_stream():
                 continue
-            sub = self.filt.slot_extract(self.state, idx)
-            if (
-                act.session.qos_mode == "drop_oldest"
-                or act.downshifted
-                or leaving
-            ) and act.steps:
-                # average only the surviving groups — mirrors
-                # run_pipelined's drop_oldest finalize exactly
-                out = self.filt.finalize(sub, steps=act.steps)
-            else:
-                out = self.filt.finalize(sub)
-            jax.block_until_ready(out)
+            t0 = time.perf_counter()
+            with obs.span(
+                "serve.finalize", "serve", session=act.name, groups=act.steps,
+                executor=self.name,
+            ):
+                sub = self.filt.slot_extract(self.state, idx)
+                if (
+                    act.session.qos_mode == "drop_oldest"
+                    or act.downshifted
+                    or leaving
+                ) and act.steps:
+                    # average only the surviving groups — mirrors
+                    # run_pipelined's drop_oldest finalize exactly
+                    out = self.filt.finalize(sub, steps=act.steps)
+                else:
+                    out = self.filt.finalize(sub)
+                jax.block_until_ready(out)
+            act.h_finalize.observe(time.perf_counter() - t0)
             report = self._report(act)
             with self.cond:
                 self.slots[idx] = None
@@ -565,28 +603,30 @@ class _SlotExecutor:
 
     def _coalesce(self, active, ready):
         """Briefly wait for straggler slots before stepping a partial
-        cohort. A full cohort steps the resident state in place (donated
-        buffers, no copies); a partial cohort pays a gather + scatter of
-        the whole slot array — worth a few ms of batching window when the
+        cohort; returns the ready slots and the seconds waited. A full
+        cohort steps the resident state in place (donated buffers, no
+        copies); a partial cohort pays a gather + scatter of the whole
+        slot array — worth a few ms of batching window when the
         co-tenants are pacing together. Bounded: after ``coalesce_s`` the
         partial cohort goes ahead, so one stalled tenant can only add the
         window, never block the others."""
         if len(ready) == len(active) or self.coalesce_s <= 0:
-            return ready
-        deadline = time.perf_counter() + self.coalesce_s
+            return ready, 0.0
+        t0 = time.perf_counter()
+        deadline = t0 + self.coalesce_s
         with obs.span(
             "serve.coalesce", "serve", executor=self.name, ready=len(ready),
             active=len(active),
         ) as sp:
             with self.cond:
                 while True:
-                    left = deadline - time.perf_counter()
+                    now = time.perf_counter()
                     active = self._steppable()  # a stream may end mid-window
                     ready = self._ready(active)
-                    if len(ready) == len(active) or left <= 0 or self._shutdown:
+                    if len(ready) == len(active) or now >= deadline or self._shutdown:
                         sp.set(ready_after=len(ready))
-                        return ready
-                    self.cond.wait(left)
+                        return ready, now - t0
+                    self.cond.wait(deadline - now)
 
     def _step_ready(self) -> None:
         active = self._steppable()
@@ -600,17 +640,17 @@ class _SlotExecutor:
                 return
             self._fold_cohort(ready, gang=True)
             return
-        ready = self._coalesce(active, ready)
+        ready, waited = self._coalesce(active, ready)
         if not ready:
             return
         if self.filt.phase_invariant:
-            self._fold_cohort(ready)
+            self._fold_cohort(ready, coalesce_s=waited)
             return
         cohorts: dict[int, list[tuple[int, _Active]]] = {}
         for i, a in ready:
             cohorts.setdefault(a.steps, []).append((i, a))
         for phase in sorted(cohorts):
-            self._fold_cohort(cohorts[phase])
+            self._fold_cohort(cohorts[phase], coalesce_s=waited)
 
     def _stage_chunks(self, idxs, items):
         """Assemble a full cohort's (capacity, N, H, W) chunk batch.
@@ -636,8 +676,11 @@ class _SlotExecutor:
         self._chunk_buf = buf
         return buf
 
-    def _fold_cohort(self, group: Sequence[tuple[int, _Active]], gang=False) -> None:
-        """One device step folding one staged chunk per cohort member."""
+    def _fold_cohort(
+        self, group: Sequence[tuple[int, _Active]], gang=False, coalesce_s=0.0
+    ) -> None:
+        """One device step folding one staged chunk per cohort member;
+        ``coalesce_s`` is the batching wait that formed the cohort."""
         if self.faults is not None:
             # scripted faults fire HERE, before any ring item is consumed:
             # a crash or stall at cohort step k never half-eats a staged
@@ -655,38 +698,56 @@ class _SlotExecutor:
                 return
             self._folding = True
         try:
-            self._fold_cohort_inner(group, gang, fault_extra_s)
+            self._fold_cohort_inner(group, gang, fault_extra_s, coalesce_s)
         finally:
             with self.cond:
                 self._folding = False
                 self.cond.notify_all()
 
+    def _cohort_kind(self, size: int, gang: bool) -> str:
+        if gang:
+            return "gang"
+        if size == 1:
+            return "lone"
+        return "full" if size == self.capacity else "partial"
+
     def _fold_cohort_inner(
         self, group: Sequence[tuple[int, _Active]], gang: bool,
-        fault_extra_s: float,
+        fault_extra_s: float, coalesce_s: float,
     ) -> None:
         t_clock0 = self.clock.now()
-        items = []  # (dev, transfer_dt, dwell_s): len>0 held, never blocks
-        for _, a in group:
-            dwell0 = a.ring.stats.dwell_s
-            dev, dt = a.ring.get()
-            # this item's staged->pickup wait, from the ring's own put
-            # timestamp (taken post-backpressure, i.e. actual insertion) —
-            # exact because this thread is the ring's only consumer
-            items.append((dev, dt, a.ring.stats.dwell_s - dwell0))
-        t_fetch = time.perf_counter()
-        idxs = [i for i, _ in group]
-        phase = group[0][1].steps
-        if not self.filt.phase_invariant and any(
-            a.steps != phase for _, a in group
-        ):
-            raise RuntimeError("phase-mixed cohort for a phase-sensitive filter")
-        t0 = time.perf_counter()
-        with obs.span(
-            "serve.cohort", "serve", executor=self.name, size=len(group),
-            gang=gang, phase=phase,
-        ):
-            if len(group) == 1 and not gang:
+        kind = self._cohort_kind(len(group), gang)
+        # the cohort's identifiers, built only for a tracer that records
+        ids = (
+            dict(
+                session=[a.name for _, a in group],
+                group=[a.steps for _, a in group],
+                executor=self.name, size=len(group), kind=kind,
+            )
+            if obs.get_tracer().enabled
+            else {}
+        )
+        # serve.dispatch and serve.block are siblings, never nested under
+        # one parent span: an idle device gap is named after the span that
+        # overlaps it most, and a parent would take every gap
+        with obs.span("serve.dispatch", "serve", **ids):
+            items = []  # (dev, transfer_dt, dwell_s): len>0 held, never blocks
+            for _, a in group:
+                dwell0 = a.ring.stats.dwell_s
+                dev, dt = a.ring.get()
+                # this item's staged->pickup wait, from the ring's own put
+                # timestamp (taken post-backpressure, i.e. actual insertion)
+                # — exact because this thread is the ring's only consumer
+                items.append((dev, dt, a.ring.stats.dwell_s - dwell0))
+            t_fetch = time.perf_counter()
+            idxs = [i for i, _ in group]
+            phase = group[0][1].steps
+            if not self.filt.phase_invariant and any(
+                a.steps != phase for _, a in group
+            ):
+                raise RuntimeError("phase-mixed cohort for a phase-sensitive filter")
+            t0 = time.perf_counter()
+            if kind == "lone":
                 # lone slot: the SINGLE-BANK step path — a 1-session
                 # scheduler run makes exactly the calls run_pipelined
                 # makes, which is what keeps it bit-identical for every
@@ -695,7 +756,7 @@ class _SlotExecutor:
                 sub = self.filt.slot_extract(self.state, i)
                 new = self.filt.step(sub, items[0][0], step_index=phase)
                 self.state = self._insert_slot(self.state, new, i)
-            elif gang:
+            elif kind == "gang":
                 # full-capacity sharded step; vacant slots ride along on a
                 # dummy chunk (their junk state is re-initialized at join)
                 by_slot = dict(zip(idxs, items))
@@ -719,7 +780,7 @@ class _SlotExecutor:
                     step_index=phase,
                     filt=self.filt,
                 )
-            elif len(group) == self.capacity:
+            elif kind == "full":
                 # whole slot array ready: fill the persistent staging
                 # buffer with donated slice writes and step the resident
                 # state in place — zero whole-array copies on the
@@ -737,20 +798,24 @@ class _SlotExecutor:
                 stacked = jnp.stack([it[0] for it in items])
                 new = self.filt.step(sub, stacked, step_index=phase)
                 self.state = self.filt.slot_scatter(self.state, new, idxs)
-            # block per cohort: per-group service latency must be the time
-            # the result actually exists, not async-dispatch time
+        # block per cohort: per-group service latency must be the time
+        # the result actually exists, not async-dispatch time
+        with obs.span("serve.block", "serve", **ids):
             jax.block_until_ready(self.state)
         t_done = time.perf_counter()
         share = (t_done - t0) / len(group)
+        step = t_done - t_fetch
         self.cohort_steps += 1
         for (i, act), (dev, dt, dwell) in zip(group, items):
             act.steps += 1
             act.frames += math.prod(dev.shape[:-2])
             act.c_transfer.inc(dt)
             act.c_compute.inc(share)
+            act.h_coalesce.observe(coalesce_s)
+            act.h_step.observe(step)
             # service latency: in-ring wait (from actual insertion) plus
-            # this cohort's fetch-to-step-done span
-            lat = dwell + (t_done - t_fetch)
+            # this cohort's fetch-to-step-done time
+            lat = dwell + step
             act.record_latency(lat)
             d = act.session.deadline_ms
             if d is not None and lat * 1e3 > d:
@@ -899,7 +964,25 @@ class SessionScheduler:
         self.metrics.describe(
             "serve.latency_s", "per-group service latency, staged -> step done (s)"
         )
-        self.metrics.describe("serve.transfer_s", "host->device transfer time (s)")
+        self.metrics.describe(
+            "serve.transfer_s",
+            "acquisition time per chunk: pull from the source + land on device (s)",
+        )
+        self.metrics.describe(
+            "serve.h2d_s", "host->device landing of a chunk, pull excluded (s)"
+        )
+        self.metrics.describe(
+            "serve.backpressure_s", "acquisition thread blocked on a full ring (s)"
+        )
+        self.metrics.describe(
+            "serve.coalesce_s", "per-group batching wait of its cohort (s)"
+        )
+        self.metrics.describe(
+            "serve.step_s", "per-group cohort time, ring fetch -> step done (s)"
+        )
+        self.metrics.describe(
+            "serve.finalize_s", "per-session slot extract + finalize (s)"
+        )
         self.metrics.describe("serve.compute_s", "per-session share of cohort compute (s)")
         self.metrics.describe("serve.deadline_misses", "groups over their soft deadline")
         self.metrics.describe("serve.discarded", "staged groups dropped at leave")
