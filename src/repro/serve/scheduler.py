@@ -39,7 +39,9 @@ Topology (one ``_SlotExecutor`` shown; the scheduler pools several)::
   cohorts, which skip the gather/scatter entirely: the resident state
   steps in place with donated buffers, and chunks land in a persistent
   staging buffer via donated slice writes (``_write_slot``) instead of a
-  fresh ``jnp.stack`` per group.
+  fresh ``jnp.stack`` per group. The window opens only while every slot
+  is steppable; with fewer, no full cohort can form, and the ready slots
+  step at once (counted in ``serve.coalesce_skips``).
 * **Compatibility.** Sessions share an executor iff their configs'
   ``DenoiseConfig.stream_key()`` match (same filter, shapes, parameters —
   scheduling-only fields excluded; ``tile_plan`` participates, so
@@ -266,6 +268,9 @@ class _SlotExecutor:
         self.on_beat = on_beat            # (name, clock.now()) liveness beat
         self.on_cohort = on_cohort        # () after each cohort fold (SLO tick)
         self.metrics = metrics if metrics is not None else obs.MetricsRegistry()
+        self.c_coalesce_skips = self.metrics.counter(
+            "serve.coalesce_skips", executor=name
+        )
         self.filt, self.state = banked_filter_init(config, mesh, banks=capacity)
         self._chunk_buf = None  # persistent staging buffer, filled in place
         self.slots: list[_Active | None] = [None] * capacity
@@ -607,10 +612,15 @@ class _SlotExecutor:
         cohort steps the resident state in place (donated buffers, no
         copies); a partial cohort pays a gather + scatter of the whole
         slot array — worth a few ms of batching window when the
-        co-tenants are pacing together. Bounded: after ``coalesce_s`` the
-        partial cohort goes ahead, so one stalled tenant can only add the
-        window, never block the others."""
+        co-tenants are pacing together. The window opens only while every
+        slot is steppable: with fewer, no straggler can complete a full
+        cohort, so the ready slots step at once (``serve.coalesce_skips``).
+        Bounded: after ``coalesce_s`` the partial cohort goes ahead, so one
+        stalled tenant can only add the window, never block the others."""
         if len(ready) == len(active) or self.coalesce_s <= 0:
+            return ready, 0.0
+        if len(active) < self.capacity:
+            self.c_coalesce_skips.inc()
             return ready, 0.0
         t0 = time.perf_counter()
         deadline = t0 + self.coalesce_s
@@ -623,7 +633,12 @@ class _SlotExecutor:
                     now = time.perf_counter()
                     active = self._steppable()  # a stream may end mid-window
                     ready = self._ready(active)
-                    if len(ready) == len(active) or now >= deadline or self._shutdown:
+                    if (
+                        len(ready) == len(active)
+                        or len(active) < self.capacity
+                        or now >= deadline
+                        or self._shutdown
+                    ):
                         sp.set(ready_after=len(ready))
                         return ready, now - t0
                     self.cond.wait(deadline - now)
@@ -904,7 +919,9 @@ class SessionScheduler:
     ``mesh`` it is pinned to the mesh's bank axis), ``max_executors`` the
     pool size, ``max_sessions``/``max_waiting`` the admission limits, and
     ``coalesce_ms`` the bounded wait for straggler slots before a partial
-    cohort steps (0 disables batching windows entirely).
+    cohort steps (0 disables batching windows entirely). The window opens
+    only while every slot of the executor is steppable, the one case in
+    which a straggler can complete a full cohort.
     """
 
     def __init__(
@@ -976,6 +993,11 @@ class SessionScheduler:
         )
         self.metrics.describe(
             "serve.coalesce_s", "per-group batching wait of its cohort (s)"
+        )
+        self.metrics.describe(
+            "serve.coalesce_skips",
+            "partial cohorts stepped without a window: too few steppable slots "
+            "to fill the executor",
         )
         self.metrics.describe(
             "serve.step_s", "per-group cohort time, ring fetch -> step done (s)"

@@ -130,6 +130,88 @@ def test_coalesce_holds_the_wait_for_a_straggler():
     assert 0.015 <= co.stats()["max"] < 0.5
 
 
+def _staggered_outputs(cfg, coalesce_ms):
+    """Two tenants on four slots, one of them 30 ms behind per group."""
+    gate = threading.Event()
+    with SessionScheduler(
+        slots_per_executor=4, max_executors=1, coalesce_ms=coalesce_ms
+    ) as sched:
+        hs = [
+            sched.submit(Session(cfg, _sleeping(_groups(cfg, s), sleep_s, gate), name=f"g{s}"))
+            for s, sleep_s in ((1, 0.0), (2, 0.03))
+        ]
+        deadline = time.monotonic() + WAIT
+        while any(h.status != "active" for h in hs):
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
+        gate.set()
+        outs = [np.asarray(h.result(timeout=WAIT)[0]) for h in hs]
+        reg = sched.metrics
+        coalesce = [reg.histogram("serve.coalesce_s", session=f"g{s}") for s in (1, 2)]
+        skips = reg.value("serve.coalesce_skips", executor="ex0")
+    return outs, coalesce, skips
+
+
+def test_coalesce_skips_the_window_when_the_slots_cannot_fill():
+    # two steppable tenants can never fill four slots: the fast tenant's
+    # partial cohorts step at once instead of waiting 50 ms for the other
+    cfg = _cfg()
+    outs, coalesce, skips = _staggered_outputs(cfg, coalesce_ms=50.0)
+    for co in coalesce:
+        assert co.count == cfg.num_groups
+        assert co.stats()["max"] == 0.0
+    assert skips > 0
+    plain, _, plain_skips = _staggered_outputs(cfg, coalesce_ms=0.0)
+    assert plain_skips == 0  # no window configured: nothing to skip
+    for a, b in zip(outs, plain):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_a_stream_ending_mid_window_ends_the_wait():
+    # three slots, all steppable: the fast tenant's second group opens the
+    # window for "held" and "short"; "short" then ends its stream, so no
+    # full cohort can form any more and the wait ends long before 10 s
+    cfg = _cfg()
+    gate, end_short, release_held = (threading.Event() for _ in range(3))
+
+    def held():
+        gs = _groups(cfg, 2)
+        gate.wait()
+        yield gs[0]
+        release_held.wait()
+        yield from gs[1:]
+
+    def short():
+        gate.wait()
+        yield _groups(cfg, 3)[0]
+        end_short.wait()
+
+    with _Recording(slots_per_executor=3, max_executors=1, coalesce_ms=10_000.0) as sched:
+        hs = [
+            sched.submit(Session(cfg, _sleeping(_groups(cfg, 1), 0.0, gate), name="fast")),
+            sched.submit(Session(cfg, held(), name="held")),
+            sched.submit(Session(cfg, short(), name="short")),
+        ]
+        deadline = time.monotonic() + WAIT
+        while any(h.status != "active" for h in hs):
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
+        gate.set()
+        fast = sched.acts["fast"]
+        while fast.steps < 1 or len(fast.ring) == 0:
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
+        time.sleep(0.5)  # the executor now waits in the window
+        end_short.set()
+        hs[0].result(timeout=WAIT)
+        release_held.set()
+        for h in hs[1:]:
+            h.result(timeout=WAIT)
+        co = sched.metrics.histogram("serve.coalesce_s", session="fast")
+    assert co.count == cfg.num_groups
+    assert 0.25 <= co.stats()["max"] < 5.0
+
+
 def test_backpressure_counts_a_producer_held_by_a_stalled_executor(fleet_factory):
     cfg = _cfg(num_groups=6)
     plan = FaultPlan().stall("ex0", at_step=1)
